@@ -42,9 +42,10 @@ DRYRUN_DIR = Path(__file__).resolve().parents[1] / "experiments" / "dryrun"
 
 
 def measure_roofline_fraction() -> dict:
-    """Run the MFU dry-run cell in a subprocess and return
-    ``{"roofline_fraction": ..., "roofline_source": ...}`` (empty dict if
-    the compile fails -- the committed snapshot then remains authoritative)."""
+    """Run the MFU dry-run cell in a subprocess (CPU placeholder devices,
+    see ``repro.launch.dryrun``) and return
+    ``{"roofline_fraction": ..., "roofline_source": ...}``; raises if the
+    compile fails."""
     from repro.core.calibration import compute_measured_mfu
 
     env = dict(os.environ,
@@ -55,12 +56,11 @@ def measure_roofline_fraction() -> dict:
         env=env, capture_output=True, text=True)
     artifact = DRYRUN_DIR / f"{MFU_ARCH}__{MFU_SHAPE}__{MFU_MESH}.json"
     if proc.returncode != 0 or not artifact.exists():
-        print(f"# measured-MFU dryrun failed:\n{proc.stderr[-2000:]}",
-              file=sys.stderr)
-        return {}
+        raise RuntimeError(
+            f"measured-MFU dryrun failed:\n{proc.stderr[-2000:]}")
     d = json.loads(artifact.read_text())
     if not d.get("ok") or d.get("skipped"):
-        return {}
+        raise RuntimeError(f"measured-MFU dryrun cell not ok: {d.get('error')}")
     frac = compute_measured_mfu(d)
     return {
         "roofline_fraction": frac,

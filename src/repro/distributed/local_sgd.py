@@ -38,23 +38,6 @@ from repro.models import build_model
 from repro.optim import make_optimizer
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names, check_vma=False):
-    """shard_map across jax API generations: ``jax.shard_map`` (>= 0.5,
-    ``axis_names`` = manual axes) when available, else the legacy
-    ``jax.experimental.shard_map`` (``auto`` = complement, ``check_rep``).
-    NOTE: on the legacy API, *partial*-manual mode (axis_names a strict
-    subset) is known to abort in the XLA SPMD partitioner for this model --
-    tests gate on ``hasattr(jax, "shard_map")`` for those paths."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False, auto=auto)
-
-
 def _inner_ctx(arch: ArchConfig, mesh: Mesh) -> ShardingCtx:
     """Sharding ctx for use INSIDE shard_map(manual='pod'): batch maps to
     'data' only and nothing may reference 'pod'."""
@@ -144,7 +127,7 @@ def build_local_sgd(arch: ArchConfig, mesh: Mesh, shape: ShapeConfig | str,
         return add_pod(new_p), add_pod(new_s), metrics
 
     pod_leading = lambda t: jax.tree.map(lambda _: P("pod"), t)  # noqa: E731
-    inner_sm = _shard_map(
+    inner_sm = jax.shard_map(
         inner_body, mesh=mesh,
         in_specs=(pod_leading(params_st_abs), pod_leading(opt_st_abs),
                   jax.tree.map(lambda _: P(("pod",)), batch_specs)),
@@ -203,7 +186,7 @@ def build_local_sgd(arch: ArchConfig, mesh: Mesh, shape: ShapeConfig | str,
             ss = jax.lax.all_gather(scale, "pod")
             return jnp.mean(dequantize_int8(qs, ss), axis=0), new_res[None]
 
-        mean, new_res = _shard_map(
+        mean, new_res = jax.shard_map(
             body, mesh=mesh, in_specs=(full_in, full_in),
             out_specs=(P(*pspec), full_in),
             axis_names=set(mesh.axis_names), check_vma=False)(x, res)

@@ -60,6 +60,7 @@ def batch_shardings(ctx: ShardingCtx, batch_specs: dict) -> dict:
 class BuiltStep:
     fn: Callable                      # jitted
     in_specs: tuple                   # abstract inputs, positional
+    in_shardings: tuple               # their shardings, positional
     ctx: ShardingCtx
     arch: ArchConfig
     kind: str
@@ -139,7 +140,8 @@ def build_train_step(arch: ArchConfig, mesh: Mesh, shape: ShapeConfig | str,
         out_shardings=(param_sh, opt_sh, None),
         donate_argnums=(0, 1),
     )
-    return BuiltStep(fn, (params_abs, opt_abs, batch_specs), ctx, arch, "train")
+    return BuiltStep(fn, (params_abs, opt_abs, batch_specs),
+                     (param_sh, opt_sh, batch_sh), ctx, arch, "train")
 
 
 # ------------------------------------------------------------ prefill --------
@@ -164,7 +166,8 @@ def build_prefill_step(arch: ArchConfig, mesh: Mesh, shape: ShapeConfig | str,
 
     fn = jax.jit(prefill_step, in_shardings=(param_sh, batch_sh),
                  out_shardings=None)
-    return BuiltStep(fn, (params_abs, batch_specs), ctx, arch, "prefill")
+    return BuiltStep(fn, (params_abs, batch_specs), (param_sh, batch_sh), ctx,
+                     arch, "prefill")
 
 
 # ------------------------------------------------------------- serve ---------
@@ -191,8 +194,8 @@ def build_serve_step(arch: ArchConfig, mesh: Mesh, shape: ShapeConfig | str) -> 
                  in_shardings=(param_sh, cache_sh, tok_sh, pos_sh),
                  out_shardings=(None, cache_sh),
                  donate_argnums=(1,))
-    return BuiltStep(fn, (params_abs, cache_abs, tok_abs, pos_abs), ctx, arch,
-                     "decode")
+    return BuiltStep(fn, (params_abs, cache_abs, tok_abs, pos_abs),
+                     (param_sh, cache_sh, tok_sh, pos_sh), ctx, arch, "decode")
 
 
 def build_step(arch: ArchConfig, mesh: Mesh, shape: ShapeConfig | str) -> BuiltStep:

@@ -5,9 +5,12 @@ Each kernel subpackage ships three modules:
   ops.py    -- jit'd public wrapper (shape plumbing, interpret-mode switch)
   ref.py    -- pure-jnp oracle used by the tests' allclose sweeps
 
-This container is CPU-only: kernels are VALIDATED with interpret=True
-(Python-level execution of the kernel body); on TPU the same pallas_call
-lowers to Mosaic.  The jnp model paths double as the oracles.
+Off the TPU (the test suite runs with JAX_PLATFORMS=cpu) kernels are
+validated with interpret=True (Python-level execution of the kernel body);
+on a TPU the same pallas_call lowers to Mosaic -- tests/test_tpu_compile.py
+compiles the codec kernels for a described v5e chip, and chip_smoke.py runs
+them on one against their oracles.  The jnp model paths double as the
+oracles.
 
 Kernels:
   flash_attention  -- fused causal/bidir attention (training/prefill)

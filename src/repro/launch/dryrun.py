@@ -1,12 +1,15 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_FLAGS")
                            or "--xla_force_host_platform_device_count=512")
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 MUST be run as its own process (``python -m repro.launch.dryrun``): the first
-two lines force 512 host placeholder devices BEFORE any jax import -- jax
-locks the device count on first init.  Tests override the count via
-REPRO_XLA_FLAGS.
+lines pin the CPU platform and force 512 host placeholder devices BEFORE any
+jax import -- jax locks the platform and device count on first init.  A
+placeholder-device compile never needs an accelerator, so a dry-run started
+next to a process that holds the chip never contends for it.  Tests override
+the count via REPRO_XLA_FLAGS.
 
 For each cell we record: memory_analysis (proves it fits), cost_analysis
 (FLOPs/bytes for the roofline), and the collective-bytes breakdown parsed

@@ -3,17 +3,23 @@
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --reduced \
         --steps 50 --batch 8 --seq 128 --mesh 1x1
 
-On real hardware, run without --mesh to get the production 16x16 pod (or
---multi-pod for 2x16x16 with --algorithm diloco for the cross-pod-efficient
-MA-SGD path).  Fault tolerance: deadline-aware checkpointing via
-PreemptionGuard; rerun the same command to resume (elastic: change
---data-workers between runs).
+Without --mesh the launcher runs pure data parallelism over every device of
+the host: a ("data","model") mesh with data = device count, model = 1.  A
+mesh with a leading "pod" axis (e.g. --mesh 2x2x2) plus --algorithm
+ma_sgd|diloco runs the cross-pod-efficient MA-SGD path.  Fault tolerance:
+deadline-aware checkpointing via PreemptionGuard; rerun the same command to
+resume (elastic: change --data-workers between runs).
+
+``train()`` is the same loop as a function, for callers that drive it in
+their own process (``chip_smoke.py``).
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import time
+from dataclasses import dataclass
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -21,59 +27,38 @@ import numpy as np
 
 from repro import checkpoint as ckpt
 from repro.configs import get_arch, get_reduced
-from repro.configs.base import ShapeConfig
+from repro.configs.base import ArchConfig, ShapeConfig
 from repro.data.tokens import TokenStream
-from repro.launch.mesh import make_mesh, make_production_mesh
-from repro.launch.specs import make_batch
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_data_mesh, make_mesh
 
 
-def _mesh_from_arg(arg: str | None, multi_pod: bool):
+def _mesh_from_arg(arg: str | None):
     if arg:
         dims = tuple(int(x) for x in arg.split("x"))
         names = (("data", "model") if len(dims) == 2
                  else ("pod", "data", "model"))
         return make_mesh(dims, names)
-    return make_production_mesh(multi_pod=multi_pod)
+    return make_data_mesh()
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="smollm-360m")
-    ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--steps", type=int, default=100)
-    ap.add_argument("--batch", type=int, default=None,
-                    help="global batch (default: arch shape train_4k)")
-    ap.add_argument("--seq", type=int, default=None)
-    ap.add_argument("--mesh", default=None, help="e.g. 1x1, 2x4, 2x2x2")
-    ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--algorithm", default=None,
-                    choices=[None, "ga_sgd", "ma_sgd", "diloco"])
-    ap.add_argument("--sync-period", type=int, default=None)
-    ap.add_argument("--compress", action="store_true")
-    ap.add_argument("--ckpt-dir", default=None)
-    ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--lifetime", type=float, default=900.0)
-    ap.add_argument("--data-workers", type=int, default=1)
-    ap.add_argument("--data-worker", type=int, default=0)
-    ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+@dataclass
+class TrainRun:
+    """What ``train`` returns: one entry per step it ran."""
+    losses: list[float]
+    step_s: list[float]   # host clock per step, ending in block_until_ready
+    params: Any           # final parameters (pod-stacked under local SGD)
+    batch: dict           # the last batch, as placed for the step
 
-    arch = get_reduced(args.arch) if args.reduced else get_arch(args.arch)
+
+def train(arch: ArchConfig, mesh, *, steps: int, batch_size: int, seq: int,
+          ckpt_dir: str | None = None, ckpt_every: int = 50,
+          lifetime: float = 900.0, data_worker: int = 0,
+          data_workers: int = 1, log_every: int = 10) -> TrainRun:
+    """Run ``steps`` train steps of ``arch`` on ``mesh`` over
+    ``TokenStream(seed=0)`` batches, resuming from ``ckpt_dir`` if it holds
+    a checkpoint."""
     tc = arch.train
-    if args.algorithm:
-        tc = dataclasses.replace(tc, algorithm=args.algorithm)
-    if args.sync_period:
-        tc = dataclasses.replace(tc, sync_period=args.sync_period)
-    if args.compress:
-        tc = dataclasses.replace(tc, compress_cross_pod=True)
-    # micro-batching needs batch % micro == 0 on arbitrary CLI batches
-    if args.batch and args.batch % max(tc.micro_batches, 1) != 0:
-        tc = dataclasses.replace(tc, micro_batches=1)
-    arch = arch.replace(train=tc)
-
-    mesh = _mesh_from_arg(args.mesh, args.multi_pod)
-    batch_size = args.batch or 8
-    seq = args.seq or 128
     shape = ShapeConfig("cli", seq, batch_size, "train")
     local_sgd = (tc.algorithm in ("ma_sgd", "diloco")
                  and "pod" in mesh.axis_names)
@@ -82,9 +67,8 @@ def main():
     from repro.optim import make_optimizer
     model = build_model(arch)
     opt = make_optimizer(tc)
-    stream = TokenStream(arch.model.vocab_size, seed=0,
-                         worker=args.data_worker,
-                         num_workers=args.data_workers)
+    stream = TokenStream(arch.model.vocab_size, seed=0, worker=data_worker,
+                         num_workers=data_workers)
 
     print(f"arch={arch.name} ({model.param_count():,} params) "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
@@ -100,68 +84,117 @@ def main():
             opt_st = jax.tree.map(lambda x: jnp.stack([x] * P),
                                   opt.init(params))
             outer = ls.init_outer_fn(params_st)
+            batch_sh = None
         else:
             from repro.distributed.step import build_train_step
             from repro.launch.specs import input_specs
             specs = {
                 k: jax.ShapeDtypeStruct((batch_size,) + v.shape[1:], v.dtype)
-                for k, v in input_specs(arch, ShapeConfig(
-                    "x", seq, batch_size, "train"))["batch"].items()}
+                for k, v in input_specs(arch, shape)["batch"].items()}
             step = build_train_step(arch, mesh, shape, batch_specs=specs)
-            params = model.init(jax.random.key(0))
-            opt_state = opt.init(params)
+            param_sh, opt_sh, batch_sh = step.in_shardings
+            params = jax.jit(model.init, out_shardings=param_sh)(
+                jax.random.key(0))
+            opt_state = jax.jit(opt.init, out_shardings=opt_sh)(params)
 
         # resume
         step0 = 0
-        if args.ckpt_dir:
-            restored, meta = ckpt.load_latest(args.ckpt_dir)
+        if ckpt_dir:
+            restored, meta = ckpt.load_latest(ckpt_dir)
             if restored is not None:
                 step0 = int(meta["step"])
-                stream.restore(meta["stream"], args.data_worker,
-                               args.data_workers)
+                stream.restore(meta["stream"], data_worker, data_workers)
                 if local_sgd:
                     params_st = jax.tree.map(jnp.asarray, restored["params"])
                     opt_st = jax.tree.map(jnp.asarray, restored["opt"])
                 else:
-                    params = jax.tree.map(jnp.asarray, restored["params"])
-                    opt_state = jax.tree.map(jnp.asarray, restored["opt"])
+                    params = jax.device_put(restored["params"], param_sh)
+                    opt_state = jax.device_put(restored["opt"], opt_sh)
                 print(f"resumed from step {step0}")
 
-        guard = ckpt.PreemptionGuard(lifetime_s=args.lifetime)
+        guard = ckpt.PreemptionGuard(lifetime_s=lifetime)
         t0 = time.time()
-        loss = float("nan")
-        for it in range(step0, args.steps):
-            b = jax.tree.map(jnp.asarray, stream.batch(batch_size, seq))
-            ts = time.time()
+        losses, step_s = [], []
+        b = None
+        for it in range(step0, steps):
+            b = jax.device_put(stream.batch(batch_size, seq), batch_sh)
+            ts = time.perf_counter()
             if local_sgd:
                 params_st, opt_st, m = ls.inner_fn(params_st, opt_st, b)
-                loss = float(np.asarray(m["loss"]).mean())
                 if (it + 1) % ls.sync_period == 0:
                     params_st, outer = ls.outer_fn(params_st, outer)
+                jax.block_until_ready((params_st, opt_st, m))
             else:
                 params, opt_state, m = step.fn(params, opt_state, b)
-                loss = float(m["loss"])
-            guard.record_step(time.time() - ts)
-            if it % args.log_every == 0 or it == args.steps - 1:
+                jax.block_until_ready((params, opt_state, m))
+            dt = time.perf_counter() - ts
+            loss = float(np.asarray(m["loss"]).mean())
+            losses.append(loss)
+            step_s.append(dt)
+            guard.record_step(dt)
+            if it % log_every == 0 or it == steps - 1:
                 print(f"step {it:5d}  loss {loss:.4f}  "
                       f"{time.time() - t0:6.1f}s")
-            if args.ckpt_dir and ((it and it % args.ckpt_every == 0)
-                                  or guard.should_checkpoint()):
+            if ckpt_dir and ((it and it % ckpt_every == 0)
+                             or guard.should_checkpoint()):
                 tree = ({"params": params_st, "opt": opt_st} if local_sgd
                         else {"params": params, "opt": opt_state})
-                ckpt.save(args.ckpt_dir, it + 1, tree,
-                          {"stream": stream.state()})
-                ckpt.retain(args.ckpt_dir, keep=2)
+                ckpt.save(ckpt_dir, it + 1, tree, {"stream": stream.state()})
+                ckpt.retain(ckpt_dir, keep=2)
                 if guard.should_checkpoint():
                     print(f"step {it}: lifetime deadline -- checkpointed; "
                           "re-invoke to resume")
                     guard.renew()
-        if args.ckpt_dir:
+        if ckpt_dir:
             tree = ({"params": params_st, "opt": opt_st} if local_sgd
                     else {"params": params, "opt": opt_state})
-            ckpt.save(args.ckpt_dir, args.steps, tree,
-                      {"stream": stream.state()})
-        print(f"done: step {args.steps}, loss {loss:.4f}")
+            ckpt.save(ckpt_dir, steps, tree, {"stream": stream.state()})
+    return TrainRun(losses, step_s, params_st if local_sgd else params, b)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default="smollm-360m")
+    ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--steps", type=int, default=100)
+    ap.add_argument("--batch", type=int, default=8, help="global batch")
+    ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--mesh", default=None,
+                    help="e.g. 1x1, 2x4, 2x2x2 (default: data over all "
+                         "devices)")
+    ap.add_argument("--algorithm", default=None,
+                    choices=[None, "ga_sgd", "ma_sgd", "diloco"])
+    ap.add_argument("--sync-period", type=int, default=None)
+    ap.add_argument("--compress", action="store_true")
+    ap.add_argument("--ckpt-dir", default=None)
+    ap.add_argument("--ckpt-every", type=int, default=50)
+    ap.add_argument("--lifetime", type=float, default=900.0)
+    ap.add_argument("--data-workers", type=int, default=1)
+    ap.add_argument("--data-worker", type=int, default=0)
+    ap.add_argument("--log-every", type=int, default=10)
+    args = ap.parse_args()
+    enable_compile_cache()
+
+    arch = get_reduced(args.arch) if args.reduced else get_arch(args.arch)
+    tc = arch.train
+    if args.algorithm:
+        tc = dataclasses.replace(tc, algorithm=args.algorithm)
+    if args.sync_period:
+        tc = dataclasses.replace(tc, sync_period=args.sync_period)
+    if args.compress:
+        tc = dataclasses.replace(tc, compress_cross_pod=True)
+    # micro-batching needs batch % micro == 0 on arbitrary CLI batches
+    if args.batch % max(tc.micro_batches, 1) != 0:
+        tc = dataclasses.replace(tc, micro_batches=1)
+    arch = arch.replace(train=tc)
+
+    run = train(arch, _mesh_from_arg(args.mesh), steps=args.steps,
+                batch_size=args.batch, seq=args.seq, ckpt_dir=args.ckpt_dir,
+                ckpt_every=args.ckpt_every, lifetime=args.lifetime,
+                data_worker=args.data_worker, data_workers=args.data_workers,
+                log_every=args.log_every)
+    loss = run.losses[-1] if run.losses else float("nan")
+    print(f"done: step {args.steps}, loss {loss:.4f}")
 
 
 if __name__ == "__main__":

@@ -55,8 +55,10 @@ class Generator:
         test pins against :func:`repro.serving.sim.serve`."""
         return self.decode_steps * lat.step_s(1)
 
-    def _prefill_loop(self, tokens: np.ndarray):
-        """Generic prefill: feed prompt tokens through decode_step."""
+    def prefill(self, tokens: np.ndarray):
+        """Generic prefill: feed prompt tokens through decode_step.
+
+        Returns (logits at the last prompt position, cache, next position)."""
         b, s = tokens.shape
         cache = self.model.init_cache(b, self.max_seq)
         logits = None
@@ -72,7 +74,7 @@ class Generator:
         prompts = np.asarray(prompts, np.int32)
         b, s = prompts.shape
         assert s + max_new_tokens <= self.max_seq
-        logits, cache, pos = self._prefill_loop(prompts)
+        logits, cache, pos = self.prefill(prompts)
         out = [prompts]
         key = jax.random.key(seed)
         tok = None

@@ -12,7 +12,7 @@ requests costs
                      model_bytes / mem_bandwidth )    # weight-streaming floor
 
 and a request of (prompt_len, new_tokens) runs ``prompt_len + new_tokens``
-decode steps — exactly the loop ``Generator._prefill_loop`` + ``generate``
+decode steps — exactly the loop ``Generator.prefill`` + ``generate``
 executes, which is what the parity test pins.
 
 KV-cache footprint (the continuous-batching packing constraint) comes from

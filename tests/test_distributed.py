@@ -11,18 +11,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-import jax
 import pytest
-
-#: the local-SGD inner step needs partial-manual shard_map (manual over
-#: "pod", auto over "data"/"model").  That is ``jax.shard_map`` on jax >=
-#: 0.5; the legacy ``jax.experimental.shard_map(auto=...)`` mode hard-aborts
-#: in the XLA SPMD partitioner for this model, so these tests require the
-#: native API (the full-manual paths are unaffected).
-requires_partial_manual_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map needs jax>=0.5 (jax.shard_map); the "
-           "legacy auto= mode aborts in XLA's SPMD partitioner")
 
 ROOT = Path(__file__).resolve().parents[1]
 ENV = {**os.environ,
@@ -55,7 +44,6 @@ def test_dryrun_reduced_multi_pod():
     assert "ok " in r.stdout
 
 
-@requires_partial_manual_shard_map
 def test_local_sgd_no_cross_pod_collectives_in_inner_step():
     """The heart of the MA-SGD-on-pods claim: the inner step's collectives
     must all stay within a pod (replica groups never span pods)."""
@@ -86,7 +74,6 @@ print(json.dumps({"inner_cross": inner["cross_pod_bytes"],
     assert out["inner_total"] > 0 and out["outer_cross"] > 0, out
 
 
-@requires_partial_manual_shard_map
 def test_local_sgd_numerics_and_sync():
     """Inner loss decreases; after the outer step all pod replicas agree."""
     script = r"""

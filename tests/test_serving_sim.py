@@ -7,7 +7,9 @@ from per-request fees / provisioned spans, KV packing never busts the HBM
 budget, and zero traffic costs exactly the idle-fleet floor.  Deterministic
 mirrors of each property run even without hypothesis installed.
 """
+import functools
 import json
+import operator
 import os
 import subprocess
 import sys
@@ -35,6 +37,12 @@ from repro.serving.arrivals import (
 
 ROOT = Path(__file__).resolve().parents[1]
 ENV = {**os.environ, "PYTHONPATH": str(ROOT / "src")}
+
+
+def _left_sum(xs) -> float:
+    """Left-to-right float sum: the order ``res.cost`` accumulates fees in
+    (``sum()`` of floats is compensated since Python 3.12)."""
+    return functools.reduce(operator.add, xs, 0.0)
 
 
 @pytest.fixture(scope="module")
@@ -179,7 +187,7 @@ def test_faas_cost_is_sum_of_per_request_fees(lat_cpu):
     res = serve(FaaSRuntime(workers=16), lat_cpu, "poisson:0.5",
                 duration_s=120.0, seed=3)
     assert res.completed > 0
-    assert res.cost == sum(res.per_request_usd)          # exact, not approx
+    assert res.cost == _left_sum(res.per_request_usd)    # exact, not approx
     # every fee is one of the two shapes the constants allow (warm/cold)
     service = lat_cpu.service_s(32, 32)
     hooks = FaaSRuntime(workers=16).serving_hooks()
@@ -270,7 +278,7 @@ def test_property_suite(lat_cpu, lat_vm):
         if res.latencies:
             assert res.p50_s <= res.p99_s
         if faas:
-            assert res.cost == sum(res.per_request_usd)
+            assert res.cost == _left_sum(res.per_request_usd)
             if res.requests == 0:
                 assert res.cost == 0.0
         else:
