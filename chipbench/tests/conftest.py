@@ -1,0 +1,9 @@
+"""The benchmark's CPU tests: the repo root (for ``chipbench``) and ``src``
+(for the program) on the path."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
