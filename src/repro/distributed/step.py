@@ -129,7 +129,8 @@ def build_train_step(arch: ArchConfig, mesh: Mesh, shape: ShapeConfig | str,
             else:
                 (_, metrics), grads = jax.value_and_grad(
                     loss_of, has_aux=True)(params, batch)
-            new_p, new_s, stats = opt.update(grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                new_p, new_s, stats = opt.update(grads, opt_state, params)
         metrics = dict(metrics)
         metrics.update(stats)
         return new_p, new_s, metrics

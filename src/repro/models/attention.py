@@ -2,8 +2,8 @@
 
 Long sequences use a chunked, online-softmax ("flash-style") pure-jnp path so
 the s x s score matrix is never materialized; the Pallas TPU kernel in
-``repro.kernels.flash_attention`` implements the same contract and is swapped
-in by the step builder when ``use_pallas=True``.
+``repro.kernels.flash_attention`` implements the same contract, but no train
+or serve step calls it.
 """
 from __future__ import annotations
 
@@ -56,10 +56,11 @@ def mla_spec(cfg: ModelConfig):
 def _sdpa(q, k, v, *, causal: bool, q_pos0: int = 0):
     """q (b,s,h,dk), k/v (b,t,m,dk|dv) -> (b,s,h,dv); GQA by head grouping.
 
-    Wrapped in named_scope("flashrgn"): on TPU this whole region runs as the
-    Pallas flash kernel (kernels/flash_attention, validated vs this exact
-    math); the dry-run analyzer uses the scope marker to substitute the
-    kernel's true HBM I/O for the jnp lowering's score materialization.
+    Wrapped in named_scope("flashrgn"), a marker for the dry-run analyzer
+    (``distributed/hlo_analysis.py``): it substitutes the Pallas flash
+    kernel's HBM I/O (kernels/flash_attention, validated vs this exact math)
+    for the score materialization of this jnp lowering.  This region runs as
+    plain XLA ops on every backend; no step swaps the kernel in.
     """
     with jax.named_scope("flashrgn"):
         b, s, h, dk = q.shape

@@ -181,8 +181,9 @@ def mamba2_block(xin, p, cfg: ModelConfig, cache=None, single_step: bool = False
         y = jnp.einsum("bn,bhpn->bhp", C[:, 0].astype(jnp.float32), st)[:, None]
         new_state = st
     else:
-        y, new_state = ssd_scan(xh, dt, p["a_log"], B, C, cfg.ssm_chunk,
-                                init_state=cc.get("state"))
+        with jax.named_scope("ssd_scan"):
+            y, new_state = ssd_scan(xh, dt, p["a_log"], B, C, cfg.ssm_chunk,
+                                    init_state=cc.get("state"))
     y = y + (xh.astype(jnp.float32)
              * p["d_skip"].astype(jnp.float32)[None, None, :, None])
     y = y.reshape(b, s, di).astype(xin.dtype)

@@ -105,20 +105,22 @@ def model_spec(cfg: ModelConfig):
 # ============================================================ forward ========
 
 def mlp_apply(x, p, cfg: ModelConfig):
-    if cfg.act == "swiglu":
-        h = jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])
-    else:
-        h = jax.nn.gelu(x @ p["w_up"])
-    h = hint(h, "batch", None, "ff")
-    # output hinted seq-sharded so the TP partial-sum lowers to
-    # reduce-scatter (Megatron-SP) instead of all-reduce + slice (§Perf L3)
-    return hint(h @ p["w_down"], "batch", "seq", "embed")
+    with jax.named_scope("mlp"):
+        if cfg.act == "swiglu":
+            h = jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])
+        else:
+            h = jax.nn.gelu(x @ p["w_up"])
+        h = hint(h, "batch", None, "ff")
+        # output hinted seq-sharded so the TP partial-sum lowers to
+        # reduce-scatter (Megatron-SP) instead of all-reduce + slice (§Perf L3)
+        return hint(h @ p["w_down"], "batch", "seq", "embed")
 
 
 def _self_attn(x, p, cfg, *, causal, positions):
-    if cfg.use_mla:
-        return attn.mla_attention(x, p, cfg, causal=causal, positions=positions)
-    return attn.gqa_attention(x, p, cfg, causal=causal, positions=positions)
+    with jax.named_scope("attn"):
+        if cfg.use_mla:
+            return attn.mla_attention(x, p, cfg, causal=causal, positions=positions)
+        return attn.gqa_attention(x, p, cfg, causal=causal, positions=positions)
 
 
 def _attn_block(x, p, cfg, *, causal, positions, ff_fn):
@@ -262,20 +264,22 @@ def forward(params, batch, cfg: ModelConfig, *, remat: str = "none",
 
     if last_only:
         x = x[:, -1:, :]
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    # logits stay in the model dtype; cross_entropy does fp32 logsumexp
-    # internally.  (§Perf iteration D8: a preferred_element_type=f32 here
-    # made every backward cotangent fp32, doubling gradient all-reduce and
-    # activation-gradient traffic model-wide.)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"])
-    logits = hint(logits, "batch", "seq", "vocab")
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        # logits stay in the model dtype; cross_entropy does fp32 logsumexp
+        # internally.  (§Perf iteration D8: a preferred_element_type=f32 here
+        # made every backward cotangent fp32, doubling gradient all-reduce and
+        # activation-gradient traffic model-wide.)
+        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"])
+        logits = hint(logits, "batch", "seq", "vocab")
     return logits.astype(jnp.float32) if last_only else logits, aux
 
 
 def loss_fn(params, batch, cfg: ModelConfig, *, remat: str = "none",
             scan_layers: bool = True):
     logits, aux = forward(params, batch, cfg, remat=remat, scan_layers=scan_layers)
-    loss = cross_entropy(logits, batch["labels"], batch.get("mask"))
+    with jax.named_scope("loss"):
+        loss = cross_entropy(logits, batch["labels"], batch.get("mask"))
     total = loss + AUX_COEF * aux
     return total, {"loss": loss, "aux": aux}
 
@@ -426,7 +430,8 @@ def _mlp_ff(p, cfg):
 
 def _attn_block_decode(x1, p, cfg, ck, cv, pos):
     h = rms_norm(x1, p["ln1"], cfg.norm_eps)
-    a, ck, cv = attn.gqa_decode(h, p["attn"], cfg, ck, cv, pos)
+    with jax.named_scope("attn"):
+        a, ck, cv = attn.gqa_decode(h, p["attn"], cfg, ck, cv, pos)
     x1 = x1 + a
     h = rms_norm(x1, p["ln2"], cfg.norm_eps)
     return x1 + mlp_apply(h, p["mlp"], cfg), ck, cv
@@ -554,9 +559,10 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig):
     else:
         raise ValueError(f"{fam} does not support decode")
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
+                            preferred_element_type=jnp.float32)
     return logits[:, 0, :], cache
 
 

@@ -1,10 +1,13 @@
 """Batched serving: prefill + decode loop against the model zoo's cache API.
 
-``Generator`` serves a batch of prompts: one prefill (cache capture for the
-dense family; token-by-token warm-up fallback otherwise) followed by greedy
+``Generator`` serves a batch of prompts: a prefill that feeds the prompt
+through ``decode_step`` one position at a time (every family), then greedy
 or temperature sampling through ``decode_step``.  The same ``serve_step`` is
 what the decode_32k / long_500k dry-run shapes lower, so everything here
-runs identically under `jit` on the production mesh.
+runs identically under `jit` on the production mesh.  Host spans in the
+profiler's trace name each phase: ``prefill`` around the prompt, then per
+generated token ``sample``, ``token_fetch`` (the host waits for the token)
+and ``decode``.
 
 Traffic-scale serving lives next door (DESIGN.md §14): open-loop arrival
 processes in :mod:`repro.serving.arrivals`, the analytic per-step
@@ -21,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.models import Model, build_model
@@ -42,12 +46,12 @@ class Generator:
     def __post_init__(self):
         self.model: Model = build_model(self.arch)
         assert self.model.cfg.supports_decode, "encoder models cannot decode"
-        self._decode_fn = jax.jit(self.model.decode_step)
+        self.decode_fn = jax.jit(self.model.decode_step)
         self.decode_steps = 0     # calls to decode_step (parity with sim)
 
     def _decode(self, *args):
         self.decode_steps += 1
-        return self._decode_fn(*args)
+        return self.decode_fn(*args)
 
     def simulated_latency_s(self, lat: LatencyModel) -> float:
         """Simulated seconds for the decode steps this Generator actually
@@ -60,12 +64,13 @@ class Generator:
 
         Returns (logits at the last prompt position, cache, next position)."""
         b, s = tokens.shape
-        cache = self.model.init_cache(b, self.max_seq)
-        logits = None
-        for pos in range(s):
-            logits, cache = self._decode(self.params, cache,
-                                         jnp.asarray(tokens[:, pos]),
-                                         jnp.int32(pos))
+        with TraceAnnotation("prefill"):
+            cache = self.model.init_cache(b, self.max_seq)
+            logits = None
+            for pos in range(s):
+                logits, cache = self._decode(self.params, cache,
+                                             jnp.asarray(tokens[:, pos]),
+                                             jnp.int32(pos))
         return logits, cache, s
 
     def generate(self, prompts: np.ndarray, max_new_tokens: int = 32,
@@ -79,15 +84,18 @@ class Generator:
         key = jax.random.key(seed)
         tok = None
         for i in range(max_new_tokens):
-            if temperature > 0:
-                key, sub = jax.random.split(key)
-                tok = jax.random.categorical(sub, logits / temperature, axis=-1)
-            else:
-                tok = jnp.argmax(logits, axis=-1)
-            out.append(np.asarray(tok, np.int32)[:, None])
-            logits, cache = self._decode(self.params, cache,
-                                         tok.astype(jnp.int32),
-                                         jnp.int32(pos + i))
+            with TraceAnnotation("sample"):
+                if temperature > 0:
+                    key, sub = jax.random.split(key)
+                    tok = jax.random.categorical(sub, logits / temperature, axis=-1)
+                else:
+                    tok = jnp.argmax(logits, axis=-1)
+            with TraceAnnotation("token_fetch"):
+                out.append(np.asarray(tok, np.int32)[:, None])
+            with TraceAnnotation("decode"):
+                logits, cache = self._decode(self.params, cache,
+                                             tok.astype(jnp.int32),
+                                             jnp.int32(pos + i))
         return np.concatenate(out, axis=1)
 
 
