@@ -51,8 +51,12 @@ class Model:
     def decode_step(self, params, cache, token, pos):
         return tfm.decode_step(params, cache, token, pos, self.cfg)
 
-    def prefill(self, params, batch, max_seq=None):
-        return tfm.prefill(params, batch, self.cfg, max_seq=max_seq)
+    @property
+    def chunked_prefill(self) -> bool:
+        return tfm.chunked_prefill(self.cfg)
+
+    def prefill_chunk(self, params, cache, tokens, start, last):
+        return tfm.prefill_chunk(params, cache, tokens, start, last, self.cfg)
 
     def prime_cross_cache(self, params, cache, image_embeds):
         return tfm.prime_cross_cache(params, cache, image_embeds, self.cfg)

@@ -161,6 +161,33 @@ def gqa_decode(x1, p, cfg: ModelConfig, cache_k, cache_v, pos, *,
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache_k, cache_v
 
 
+def gqa_prefill_chunk(x, p, cfg: ModelConfig, cache_k, cache_v, start):
+    """``gqa_decode`` for C tokens at positions start .. start+C-1.
+
+    x (b,C,d); cache_k/v (b,S,m,dk); start: scalar int.  Writes the chunk's
+    K/V at ``start``, then scores each query against the whole cache, masked
+    to the positions at or before its own."""
+    b, c, _ = x.shape
+    S, m = cache_k.shape[1], cache_k.shape[2]
+    positions = start + jnp.arange(c)
+    q = rope(jnp.einsum("bsd,dhk->bshk", x, p["wq"]), positions, cfg.rope_theta)
+    k, v = gqa_prefill_kv(x, p, cfg, positions=positions)
+    cache_k = jax.lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype),
+                                           (0, start, 0, 0))
+    cache_v = jax.lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype),
+                                           (0, start, 0, 0))
+    h, dk = q.shape[2], q.shape[3]
+    qg = q.reshape(b, c, m, h // m, dk)
+    cache_k = hint(cache_k, "batch", "kv_seq", "kv_heads", "head_dim")
+    cache_v = hint(cache_v, "batch", "kv_seq", "kv_heads", "head_dim")
+    scores = jnp.einsum("bsmgk,btmk->bmgst", qg, cache_k) / (dk ** 0.5)
+    valid = jnp.arange(S)[None, :] <= positions[:, None]
+    probs = softmax_fp32(scores, where=valid[None, None, None])
+    out = jnp.einsum("bmgst,btmv->bsmgv", probs.astype(cache_v.dtype), cache_v)
+    out = out.reshape(b, c, h, cache_v.shape[-1])
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache_k, cache_v
+
+
 # ------------------------------------------------------------- MLA block -----
 
 def _mla_qkv(x, p, cfg: ModelConfig, positions):

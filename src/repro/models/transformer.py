@@ -424,14 +424,10 @@ def scan_decode(body, x0, xs, cache):
     return x, jax.tree.unflatten(tdef, leaves)
 
 
-def _mlp_ff(p, cfg):
-    return lambda h: mlp_apply(h, p, cfg)
-
-
-def _attn_block_decode(x1, p, cfg, ck, cv, pos):
+def _attn_block_decode(x1, p, cfg, ck, cv, pos, attend=attn.gqa_decode):
     h = rms_norm(x1, p["ln1"], cfg.norm_eps)
     with jax.named_scope("attn"):
-        a, ck, cv = attn.gqa_decode(h, p["attn"], cfg, ck, cv, pos)
+        a, ck, cv = attend(h, p["attn"], cfg, ck, cv, pos)
     x1 = x1 + a
     h = rms_norm(x1, p["ln2"], cfg.norm_eps)
     return x1 + mlp_apply(h, p["mlp"], cfg), ck, cv
@@ -566,46 +562,32 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig):
     return logits[:, 0, :], cache
 
 
-# ============================================================ prefill ========
+def chunked_prefill(cfg: ModelConfig) -> bool:
+    """Whether a prompt goes in through ``prefill_chunk``: the dense family,
+    whose decode block is ``gqa_decode`` over a {"k","v"} cache.  MoE's
+    capacity dispatch would route b*C tokens otherwise than b; MLA, SSM,
+    hybrid and VLM blocks have no chunk capture."""
+    return cfg.family == "dense"
 
-def prefill(params, batch, cfg: ModelConfig, max_seq: int | None = None):
-    """Run the prompt, return (logits_last (b,v), filled cache).
 
-    For simplicity the cache is sized to the prompt length (or max_seq) and
-    K/V are recomputed via the standard forward plus per-layer K/V capture.
-    """
-    tokens = batch["tokens"]
-    b, s = tokens.shape
-    S = max_seq or s
-    logits, _ = forward(params, batch, cfg)
-    cache = init_cache(cfg, b, S)
-    positions = jnp.arange(s)
-    x = jnp.take(params["embed"], tokens, axis=0)
-    fam = cfg.family
+def prefill_chunk(params, cache, tokens, start, last, cfg: ModelConfig):
+    """tokens (b,C) int32 at positions start .. start+C-1 into the cache;
+    start, last scalar int32 -> (logits (b,v) fp32 of chunk row ``last``,
+    new cache).  Rows past the prompt may hold anything: decode overwrites
+    each position before any query can see it."""
+    if not chunked_prefill(cfg):
+        raise NotImplementedError(f"chunked prefill for family {cfg.family!r}")
+    x = jnp.take(params["embed"], tokens, axis=0)  # (b,C,d)
 
-    if fam in ("dense", "moe") and not cfg.use_mla:
-        def body(carry, bp):
-            h = rms_norm(carry, bp["ln1"], cfg.norm_eps)
-            k, v = attn.gqa_prefill_kv(h, bp["attn"], cfg, positions=positions)
-            if fam == "dense":
-                ff = _mlp_ff(bp["mlp"], cfg)
-                carry = _attn_block(carry, bp, cfg, causal=True,
-                                    positions=positions, ff_fn=ff)
-            else:
-                h2 = rms_norm(carry, bp["ln1"], cfg.norm_eps)
-                carry = carry + _self_attn(h2, bp["attn"], cfg, causal=True,
-                                           positions=positions)
-                hh = rms_norm(carry, bp["ln2"], cfg.norm_eps)
-                y, _a = moe_mod.moe_block(hh, bp["moe"], cfg, _moe_groups())
-                carry = carry + y
-            return carry, (k, v)
-        _, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-        cache["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], ks.astype(cache["k"].dtype), (0, 0, 0, 0, 0))
-        cache["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], vs.astype(cache["v"].dtype), (0, 0, 0, 0, 0))
-        return logits[:, -1, :], cache
-
-    raise NotImplementedError(
-        f"prefill cache capture for family {fam!r}: use decode-from-scratch or "
-        "the serving layer")
+    def body(carry, bp, sl):
+        y, ck, cv = _attn_block_decode(carry, bp, cfg, sl["k"], sl["v"], start,
+                                       attend=attn.gqa_prefill_chunk)
+        return y, {"k": ck, "v": cv}
+    x, cache = scan_decode(body, x, params["blocks"],
+                           {"k": cache["k"], "v": cache["v"]})
+    x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
+                            preferred_element_type=jnp.float32)
+    return logits[:, 0, :], cache

@@ -13,7 +13,8 @@ requests costs
 
 and a request of (prompt_len, new_tokens) runs ``prompt_len + new_tokens``
 decode steps — exactly the loop ``Generator.prefill`` + ``generate``
-executes, which is what the parity test pins.
+executes for families that prefill token by token, which is what the
+parity test pins (dense models prefill in chunks).
 
 KV-cache footprint (the continuous-batching packing constraint) comes from
 the config dims: per-token K+V bytes for attention families, the MLA latent

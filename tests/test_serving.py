@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import serving
 from repro.configs import get_reduced
 from repro.models import build_model
 from repro.serving import Generator, perplexity
@@ -60,3 +61,64 @@ def test_perplexity_finite(small):
     toks = rng.integers(0, arch.model.vocab_size, (2, 16)).astype(np.int32)
     p = perplexity(model, params, toks)
     assert np.isfinite(p) and p > 1.0
+
+
+# ------------------------------------------------------- chunked prefill ----
+
+C = 8   # PREFILL_CHUNK in these tests: a small C with a small cache
+
+
+@pytest.fixture(scope="module", params=[32, 20], ids=["cache32", "cache20"])
+def chunked(request, small):
+    """A Generator whose prefill feeds chunks of C; one cache length a
+    multiple of C, one not (a 2C+3 prompt then shifts its last chunk back)."""
+    arch, model, params = small
+    mp = pytest.MonkeyPatch()
+    mp.setattr(serving, "PREFILL_CHUNK", C)
+    yield model, params, Generator(arch, params, max_seq=request.param)
+    mp.undo()
+
+
+def _token_loop(model, params, prompts, max_seq, new_tokens=0):
+    """The token-by-token reference: decode_step at every prompt position,
+    then greedy decode -> (logits after the prompt, cache, tokens)."""
+    step = jax.jit(model.decode_step)
+    b, s = prompts.shape
+    cache = model.init_cache(b, max_seq)
+    for pos in range(s):
+        logits, cache = step(params, cache, jnp.asarray(prompts[:, pos]),
+                             jnp.int32(pos))
+    first, out, lg = logits, [prompts], logits
+    for i in range(new_tokens):
+        tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        out.append(np.asarray(tok)[:, None])
+        lg, cache = step(params, cache, tok, jnp.int32(s + i))
+    return first, cache, np.concatenate(out, axis=1)
+
+
+@pytest.mark.parametrize("s", [1, C - 1, C, C + 1, 2 * C + 3])
+def test_chunked_prefill_matches_token_loop(chunked, s):
+    model, params, gen = chunked
+    prompts = np.random.default_rng(s).integers(
+        0, model.cfg.vocab_size, (2, s)).astype(np.int32)
+    before = gen.prefill_chunks
+    logits, cache, pos = gen.prefill(prompts)
+    want, ref_cache, _ = _token_loop(model, params, prompts, gen.max_seq)
+    assert pos == s and gen.prefill_chunks - before == -(-s // C)
+    np.testing.assert_allclose(logits, want, rtol=1e-5,
+                               atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    for k in ("k", "v"):
+        got, ref = cache[k][:, :, :s], ref_cache[k][:, :, :s]
+        np.testing.assert_allclose(got, ref, rtol=1e-5,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(ref))))
+    assert gen.prefill_fn._cache_size() == 1      # one compile, every length
+
+
+def test_chunked_generate_matches_token_loop(chunked):
+    model, params, gen = chunked
+    prompts = np.random.default_rng(7).integers(
+        0, model.cfg.vocab_size, (2, gen.max_seq - 8)).astype(np.int32)
+    _, _, want = _token_loop(model, params, prompts, gen.max_seq, new_tokens=8)
+    before = gen.decode_steps
+    np.testing.assert_array_equal(gen.generate(prompts, max_new_tokens=8), want)
+    assert gen.decode_steps - before == 8

@@ -301,17 +301,23 @@ def test_property_suite(lat_cpu, lat_vm):
 
 # ------------------------------------------------------ Generator parity ----
 
-@pytest.fixture(scope="module")
-def reduced_gen():
+def _reduced_gen(name):
     jax = pytest.importorskip("jax")
     from repro.configs import get_reduced
     from repro.models import build_model
     from repro.serving import Generator
-    arch = get_reduced("smollm-360m")
+    arch = get_reduced(name)
     arch = arch.replace(model=arch.model.replace(dtype="float32"))
     model = build_model(arch)
     params = model.init(jax.random.key(0))
     return arch, Generator(arch, params, max_seq=32)
+
+
+@pytest.fixture(scope="module")
+def reduced_gen():
+    """An ssm Generator: it prefills token by token, the loop that
+    ``LatencyModel.request_steps`` mirrors (dense models prefill in chunks)."""
+    return _reduced_gen("mamba2-370m")
 
 
 def test_sim_latency_pins_generator_decode_loop(reduced_gen):
@@ -326,7 +332,7 @@ def test_sim_latency_pins_generator_decode_loop(reduced_gen):
     assert gen.decode_steps == 12                 # 7 prefill + 5 decode
 
     hooks = IaaSRuntime(workers=1).serving_hooks()
-    lat = LatencyModel.from_arch("smollm_360m", flops=hooks.flops,
+    lat = LatencyModel.from_arch("mamba2_370m", flops=hooks.flops,
                                  mem_bandwidth=hooks.mem_bandwidth,
                                  reduced=True)
     want = gen.simulated_latency_s(lat)           # decode_steps * step_s(1)
@@ -338,13 +344,33 @@ def test_sim_latency_pins_generator_decode_loop(reduced_gen):
     assert warm_vm.latencies[0] == want           # byte-identical
 
     faas_hooks = FaaSRuntime(workers=1).serving_hooks()
-    lat_f = LatencyModel.from_arch("smollm_360m", flops=faas_hooks.flops,
+    lat_f = LatencyModel.from_arch("mamba2_370m", flops=faas_hooks.flops,
                                    mem_bandwidth=faas_hooks.mem_bandwidth,
                                    reduced=True)
     warm_faas = serve(FaaSRuntime(workers=1), lat_f, trace, duration_s=30.0,
                       prompt_len=7, new_tokens=5, prewarm=1)
     assert warm_faas.cold_starts == 0
     assert warm_faas.latencies[0] == gen.simulated_latency_s(lat_f)
+
+
+@pytest.mark.parametrize("name,chunks,steps", [
+    ("smollm-360m", 1, 5),       # dense: one prefill chunk, then 5 decode
+    ("mamba2-370m", 0, 12),      # ssm: 7 prompt positions + 5 decode
+    ("grok-1-314b", 0, 12),      # moe: capacity routing stays per token
+])
+def test_generator_prefill_path_by_family(name, chunks, steps):
+    arch, gen = _reduced_gen(name)
+    prompts = np.random.default_rng(0).integers(
+        0, arch.model.vocab_size, (1, 7)).astype(np.int32)
+    gen.generate(prompts, max_new_tokens=5)
+    assert (gen.prefill_chunks, gen.decode_steps) == (chunks, steps)
+    assert gen.model.chunked_prefill == (chunks > 0)
+    if chunks:   # the simulator models the token loop only: refuse, loudly
+        with pytest.raises(AssertionError, match="chunked prefill"):
+            hooks = IaaSRuntime(workers=1).serving_hooks()
+            gen.simulated_latency_s(LatencyModel.from_arch(
+                name, flops=hooks.flops, mem_bandwidth=hooks.mem_bandwidth,
+                reduced=True))
 
 
 # --------------------------------------------------- autoscaler suite -------

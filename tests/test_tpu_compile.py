@@ -1,8 +1,9 @@
-"""The codec kernels compile for a TPU v5e chip (Mosaic, not interpret mode).
+"""The codec kernels and the serving prefill compile for a TPU v5e chip.
 
 Ahead-of-time compiles for a described v5e device, without a chip: the
-smollm-360m update size the codecs carry on the main path, and a ragged
-1000-element vector.  The topology is described inside a fixture, never at
+codec kernels (Mosaic, not interpret mode) at the smollm-360m update size
+the codecs carry on the main path and at a ragged 1000-element vector, and
+smollm-360m's chunked prefill program at the serve cell's shapes.  The topology is described inside a fixture, never at
 import: only one process at a time may load the TPU library, and the suite
 runs under several workers.  The persistent compilation cache is off around
 these compiles, since an entry compiled for a described chip cannot be
@@ -58,3 +59,24 @@ def test_codec_kernel_compiles_for_v5e(one_chip, kernel, size):
                                  backend="kernel")
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_prefill_chunk_fits_one_v5e(one_chip):
+    """smollm-360m's chunk program at b16, C256, cache 768, bf16: it
+    compiles for one chip under its own name, and its arguments plus
+    temporaries fit the chip's 16 GB."""
+    model = build_model(get_arch("smollm-360m"))
+    assert model.cfg.dtype == "bfloat16"
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, model.abstract())
+    cache = jax.tree.map(on_chip, model.init_cache(16, 768, abstract=True))
+    tokens = on_chip(jax.ShapeDtypeStruct((16, 256), jnp.int32))
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    compiled = jax.jit(model.prefill_chunk).lower(
+        params, cache, tokens, scalar, scalar).compile()
+    assert compiled.as_text().startswith("HloModule jit_prefill_chunk")
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
