@@ -1,17 +1,25 @@
 """Attention blocks: GQA (causal / bidirectional / cross), MLA, decode paths.
 
-Long sequences use a chunked, online-softmax ("flash-style") pure-jnp path so
-the s x s score matrix is never materialized; the Pallas TPU kernel in
-``repro.kernels.flash_attention`` implements the same contract, but no train
-or serve step calls it.
+Causal GQA self-attention on a TPU (training, and any full-sequence forward)
+runs as JAX's splash attention kernel: blockwise online softmax that skips
+the blocks above the diagonal, with its own dq/dkv backward, so the s x s
+score square never reaches HBM (``flash_ok`` says when).  Every other call
+takes plain jnp: ``_sdpa`` materializes the fp32 scores, and sequences longer
+than ``CHUNK_THRESHOLD`` go through ``_sdpa_chunked``, which maps over query
+chunks.  The in-repo forward-only kernel ``repro.kernels.flash_attention`` is
+on no step's path.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import hint
+from repro.distributed.sharding import current, hint
 from repro.models.common import rope, spec, softmax_fp32
 
 import os
@@ -22,6 +30,13 @@ import os
 # EXPERIMENTS.md §Perf iteration L1)
 CHUNK_THRESHOLD = int(os.environ.get("REPRO_ATTN_CHUNK_THRESHOLD", 8192))
 Q_CHUNK = int(os.environ.get("REPRO_ATTN_Q_CHUNK", 1024))
+
+# The splash kernel's query and key blocks, forward and backward: the largest
+# of FLASH_BLOCK, FLASH_BLOCK/2, ... down to FLASH_MIN_BLOCK that divides the
+# sequence; dq and dk/dv come from separate backward kernels.  Swept on a v5e
+# at smollm-360m's train shapes (scripts/flash_block_sweep.py, PERF.md §6).
+FLASH_BLOCK = 512
+FLASH_MIN_BLOCK = 128
 
 
 # ------------------------------------------------------------------ specs ----
@@ -60,7 +75,9 @@ def _sdpa(q, k, v, *, causal: bool, q_pos0: int = 0):
     (``distributed/hlo_analysis.py``): it substitutes the Pallas flash
     kernel's HBM I/O (kernels/flash_attention, validated vs this exact math)
     for the score materialization of this jnp lowering.  This region runs as
-    plain XLA ops on every backend; no step swaps the kernel in.
+    plain XLA ops; on a TPU, causal GQA self-attention does not reach it but
+    runs as the splash kernel (``flash_sdpa``), so the analyzer's substitution
+    holds for CPU lowerings only.
     """
     with jax.named_scope("flashrgn"):
         b, s, h, dk = q.shape
@@ -96,9 +113,95 @@ def _sdpa_chunked(q, k, v, *, causal: bool, q_chunk: int = Q_CHUNK):
 
 
 def sdpa(q, k, v, *, causal: bool):
+    """q (b,s,h,dk), k/v (b,t,m,dk|dv) -> (b,s,h,dv).  Causal self-attention
+    on a TPU runs as the splash kernel where ``flash_ok`` holds; every other
+    call takes the jnp paths."""
+    if flash_ok(q, k, v, causal=causal):
+        with jax.named_scope("flash"):
+            return flash_sdpa(q, k, v)
     if q.shape[1] > CHUNK_THRESHOLD:
         return _sdpa_chunked(q, k, v, causal=causal)
     return _sdpa(q, k, v, causal=causal)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash_block(s: int) -> int:
+    """The kernel's block for sequence length s; 0 where none divides it."""
+    blk = FLASH_BLOCK
+    while blk >= FLASH_MIN_BLOCK and s % blk:
+        blk //= 2
+    return blk if blk >= FLASH_MIN_BLOCK else 0
+
+
+def flash_ok(q, k, v, *, causal: bool) -> bool:
+    """Whether ``sdpa`` of these operands runs as the splash kernel: on a
+    TPU, causal self-attention (queries and keys over the same s positions,
+    both from position 0), K and V of one head dim, s a multiple of a kernel
+    block, and no mesh "model" axis over 1 (the kernel splits only over the
+    batch)."""
+    ctx = current()
+    model = 1 if ctx is None else ctx.mesh.shape.get("model", 1)
+    s = q.shape[1]
+    return (_on_tpu() and causal and k.shape[1] == s
+            and k.shape[-1] == v.shape[-1] and _flash_block(s) > 0
+            and model == 1)
+
+
+def block_sizes(blk: int, *, fused: bool = False) -> splash.BlockSizes:
+    """Every query and key block of the kernel, forward and backward, blk;
+    dq and dk/dv from separate backward kernels, or from one if ``fused``."""
+    dq = {} if fused else {"block_q_dq": blk, "block_kv_dq": blk}
+    return splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk, block_q_dkv=blk,
+        block_kv_dkv=blk, block_kv_dkv_compute=blk, use_fused_bwd_kernel=fused,
+        **dq)
+
+
+@functools.lru_cache(maxsize=None)
+def splash_kernel(g: int, s: int, blocks: splash.BlockSizes, interpret: bool):
+    """One KV head's kernel, heads first: g query heads (g,s,dk) over one K/V
+    (s,dk), causal, unscaled.  Its mask tables are made as constants even
+    when the first call comes inside a trace, since the cache hands them to
+    later traces."""
+    mask = splash.MultiHeadMask([splash.CausalMask((s, s))] * g)
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mqa(mask, block_sizes=blocks, head_shards=1,
+                                      q_seq_shards=1, interpret=interpret)
+
+
+def flash_sdpa(q, k, v, *, interpret: bool = False):
+    """Causal GQA self-attention through the splash kernel, in ``_sdpa``'s
+    layout: q (b,s,h,dk), k/v (b,s,m,dk) -> (b,s,h,dk).  One MQA kernel per
+    KV head over its g = h/m query heads, heads first, mapped over batch and
+    KV heads; the scale is folded into q.  Under a mesh of several devices the
+    kernel runs inside ``shard_map`` over the batch axes, so q, k and v stay
+    where they are; mesh axes that an enclosing ``shard_map`` already holds
+    manual (local SGD's "pod") stay outside it."""
+    b, s, h, dk = q.shape
+    m = k.shape[2]
+    kernel = jax.vmap(jax.vmap(splash_kernel(
+        h // m, s, block_sizes(_flash_block(s)), interpret)))
+
+    def run(q, k, v):
+        def heads_first(x):
+            return x.transpose(0, 2, 1, 3)
+        qg = heads_first(q * dk ** -0.5).reshape(-1, m, h // m, s, dk)
+        out = kernel(qg, heads_first(k), heads_first(v))
+        return heads_first(out.reshape(-1, h, s, dk))
+
+    ctx = current()
+    if ctx is None or ctx.mesh.size == 1:
+        return run(q, k, v)
+    outer = jax.sharding.get_abstract_mesh()
+    manual = set(outer.manual_axes)
+    mesh = outer if manual else ctx.mesh
+    part = P(ctx.fit_axes(b, ctx.map["batch"]), None, None, None)
+    return jax.shard_map(run, mesh=mesh, in_specs=(part, part, part),
+                         out_specs=part, axis_names=set(mesh.axis_names) - manual,
+                         check_vma=False)(q, k, v)
 
 
 # ------------------------------------------------------------- GQA block -----

@@ -1,13 +1,19 @@
 """Model-zoo correctness: decode-vs-forward consistency (the strongest cache
-test), SSD chunked-vs-recurrence, MLA absorbed decode, conv cache."""
+test), SSD chunked-vs-recurrence, MLA absorbed decode, conv cache, and the
+splash kernel path of causal self-attention against the jnp path."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.configs import ARCH_IDS, get_reduced
+from repro.distributed.sharding import ShardingCtx, use_sharding
 from repro.launch.specs import make_batch
-from repro.models import build_model
+from repro.models import attention, build_model
+from repro.models.common import init_params
 from repro.models.ssm import _conv1d_causal, ssd_scan
 
 DECODE_ARCHS = [n for n in ARCH_IDS if n != "hubert-xlarge"]
@@ -160,3 +166,116 @@ def test_masked_loss_ignores_unmasked():
                              (batch["labels"] + 7) % arch.model.vocab_size)
     loss2, _ = model.loss(params, b2)
     np.testing.assert_allclose(float(loss1), float(loss2), rtol=1e-6)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("h,m,s,block", [(15, 5, 256, None), (6, 2, 256, None),
+                                         (15, 5, 512, 128)],
+                         ids=["15-5", "6-2", "15-5-s512-block128"])
+def test_flash_sdpa_matches_sdpa(monkeypatch, h, m, s, block, dtype):
+    """The splash kernel (interpret mode) against the jnp ``_sdpa`` at a GQA
+    shape, head_dim 64, causal: the output and the gradients of q, k and v.
+    At s = 256 the kernel runs one 256 block; with 128 blocks at s = 512 it
+    runs 4 x 4, so the online softmax across key blocks, the skipping of the
+    blocks above the diagonal and the dq/dkv backward over several blocks
+    are checked too.  In bf16 both are held to a float32 reference, and the
+    kernel (fp32 scores) is at least as close to it as ``_sdpa`` (bf16
+    scores)."""
+    if block:
+        monkeypatch.setattr(attention, "FLASH_BLOCK", block)
+    assert attention._flash_block(s) == (block or s)
+    b, d = 1, 64
+    ks = jax.random.split(jax.random.key(0), 4)
+    q = jax.random.normal(ks[0], (b, s, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (b, s, m, d), jnp.float32)
+    v = jax.random.normal(ks[2], (b, s, m, d), jnp.float32)
+    w = jax.random.normal(ks[3], (b, s, h, d), jnp.float32)
+
+    def flash(q, k, v):
+        return attention.flash_sdpa(q, k, v, interpret=True)
+
+    def plain(q, k, v):
+        return attention._sdpa(q, k, v, causal=True)
+
+    def out_and_grads(f, dt):
+        args = [x.astype(dt) for x in (q, k, v)]
+        out, vjp = jax.vjp(f, *args)
+        return [out] + list(vjp(w.astype(dt)))
+
+    got = out_and_grads(jax.jit(flash), dtype)
+    base = out_and_grads(plain, dtype)
+    # the kernel is cached with its mask tables: a second trace reuses them
+    again = jax.jit(lambda *a: flash(*a))(*[x.astype(dtype) for x in (q, k, v)])
+    np.testing.assert_array_equal(again, got[0])
+    if dtype == "float32":
+        for a, r in zip(got, base):
+            assert _rel(a, r) < 1e-5
+        return
+    ref = out_and_grads(plain, "float32")
+    for a, r, f32 in zip(got, base, ref):
+        assert _rel(a, r) < 1e-2
+        assert _rel(a, f32) <= 1.05 * _rel(r, f32)
+
+
+GQA_CASES = ["causal_self", "bidirectional", "cross", "ragged", "model_axis",
+             "head_dims"]
+
+
+@pytest.mark.parametrize("case", GQA_CASES)
+def test_attention_takes_kernel_only_where_it_applies(monkeypatch, case):
+    """On a TPU, causal GQA self-attention takes the kernel and matches the
+    jnp path in output and gradients; a call that fails a precondition
+    (bidirectional, cross, a length no kernel block divides, a mesh "model"
+    axis over 1, K and V of different head dims as in MLA) never calls the
+    kernel and gives the jnp path's result to the bit."""
+    cfg = get_reduced("smollm-360m").model.replace(
+        dtype="float32", d_model=128, num_heads=6, num_kv_heads=2, head_dim=64)
+    s = 200 if case == "ragged" else 256
+    kx, kc = jax.random.split(jax.random.key(2))
+    x = jax.random.normal(kx, (2, s, cfg.d_model), jnp.float32)
+    if case == "model_axis":
+        q = k = v = x.reshape(2, s, 2, 64)
+        monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+        assert attention.flash_ok(q, k, v, causal=True)
+        ctx = ShardingCtx(AbstractMesh((1, 2), ("data", "model")), get_reduced(
+            "smollm-360m").sharding)
+        with use_sharding(ctx):
+            assert not attention.flash_ok(q, k, v, causal=True)
+        return
+    if case == "head_dims":
+        p = {"q": x.reshape(2, s, 2, 64), "v": x[..., :64].reshape(2, s, 2, 32)}
+
+        def run(x, p):
+            return attention.sdpa(p["q"], p["q"], p["v"], causal=True)
+    else:
+        p = init_params(jax.random.key(1), attention.gqa_spec(cfg), "float32")
+        kw = dict(causal=case != "bidirectional", positions=jnp.arange(s))
+        if case == "cross":
+            kw.update(kv_src=jax.random.normal(kc, (2, 64, cfg.d_model)),
+                      use_rope=False)
+
+        def run(x, p):
+            return attention.gqa_attention(x, p, cfg, **kw)
+
+    def out_and_grads():
+        out, vjp = jax.vjp(run, x, p)
+        return [out] + jax.tree.leaves(vjp(jnp.ones_like(out)))
+
+    parent = out_and_grads()
+    calls = []
+    kernel = functools.partial(attention.flash_sdpa, interpret=True)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "flash_sdpa",
+                        lambda *a: calls.append(a) or kernel(*a))
+    got = out_and_grads()
+    assert bool(calls) == (case == "causal_self")
+    for a, r in zip(got, parent):
+        if calls:
+            assert _rel(a, r) < 1e-5
+        else:
+            np.testing.assert_array_equal(a, r)
