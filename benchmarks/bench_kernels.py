@@ -29,7 +29,6 @@ import numpy as np
 from benchmarks.common import emit, emit_root, timeit
 from repro.distributed.roofline import HBM_BW, PEAK_FLOPS
 from repro.kernels.decode_attention.ref import decode_attention_ref
-from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.quant8.ops import int8_roundtrip
 from repro.kernels.quant8.ref import quantize8_ref
 from repro.kernels.topk_ef.ops import topk_ef
@@ -77,19 +76,8 @@ def run(quick: bool = True):
     rows = []
     rng = np.random.default_rng(0)
 
-    # flash attention fwd: b*h=8, s=2048, d=128
-    bh, s, d = 8, 2048, 128
-    q = jnp.asarray(rng.standard_normal((bh, s, d)), jnp.bfloat16)
-    f = jax.jit(lambda q, k, v: attention_ref(q, k, v, causal=True,
-                                              sm_scale=d ** -0.5))
-    t = timeit(lambda: jax.block_until_ready(f(q, q, q)))
-    flops = 4 * bh * s * s * d
-    rows.append({"name": "kern_flash_attention_ref", "us_per_call": t * 1e6,
-                 "derived": f"cpu_gflops={flops / t / 1e9:.1f};"
-                            f"tpu_floor_us={flops / PEAK_FLOPS * 1e6:.1f}"})
-
     # decode attention: b*m=16, S=32768, d=128, g=8
-    bm, g, S = 16, 8, 32768 if not quick else 8192
+    bm, g, S, d = 16, 8, 32768 if not quick else 8192, 128
     qd = jnp.asarray(rng.standard_normal((bm, g, d)), jnp.bfloat16)
     kd = jnp.asarray(rng.standard_normal((bm, S, d)), jnp.bfloat16)
     fd = jax.jit(lambda q, k, v: decode_attention_ref(q, k, v, S,

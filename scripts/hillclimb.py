@@ -7,8 +7,7 @@ breakdown.  Each hypothesis->change->measure iteration is one command:
 
   PYTHONPATH=src python scripts/hillclimb.py --arch llama3-405b \
       --shape train_4k --mesh 16x16 \
-      --set train.remat=dots --set sharding.seq=None \
-      --env REPRO_ATTN_CHUNK_THRESHOLD=8192 --tag L3
+      --set train.remat=dots --set sharding.seq=None --tag L3
 """
 import argparse     # noqa: E402
 import dataclasses  # noqa: E402
@@ -60,7 +59,6 @@ def main():
     ap.add_argument("--save", action="store_true")
     args = ap.parse_args()
 
-    # env overrides must be set before repro imports read them
     import jax  # noqa: F401
     from repro.configs import get_arch
     from repro.distributed import roofline as rl
@@ -118,13 +116,11 @@ def main():
     d.update(extra)
     d["tag"] = args.tag
     d["sets"] = args.sets
-    d["env"] = {k: v for k, v in os.environ.items()
-                if k.startswith("REPRO_ATTN")}
     d["temp_bytes"] = int(getattr(mem, "temp_size_in_bytes", 0))
     d["t_build_s"] = round(time.time() - t0, 1)
 
     print(f"== {args.arch} x {args.shape} x {args.mesh} [{args.tag}] ==")
-    print(f"  sets: {args.sets}  env: {d['env']}")
+    print(f"  sets: {args.sets}")
     print(f"  t_compute    = {rep.t_compute:.3f} s")
     print(f"  t_memory     = {rep.t_memory:.3f} s")
     print(f"  t_collective = {rep.t_collective:.3f} s  (operand-bytes model)")

@@ -13,8 +13,13 @@ them on one against their oracles.  The jnp model paths double as the
 oracles.
 
 Kernels:
-  flash_attention  -- fused causal/bidir attention (training/prefill)
-  decode_attention -- flash-decoding over a KV cache (serve_step)
-  ssd_scan         -- Mamba2 SSD chunk kernel with carried state
+  decode_attention -- flash-decoding over a KV cache (on no model path yet)
+  ssd_scan         -- Mamba2 SSD chunk kernel with carried state (on no
+                      model path yet)
   quant8           -- blockwise int8 quantize/dequant (gradient compression)
+  topk_ef          -- magnitude top-k with error feedback (gradient
+                      compression)
+
+Causal training attention on a TPU runs JAX's own splash attention kernel
+(``repro.models.attention.flash_sdpa``), not one of these.
 """

@@ -3,11 +3,12 @@
 Causal GQA self-attention on a TPU (training, and any full-sequence forward)
 runs as JAX's splash attention kernel: blockwise online softmax that skips
 the blocks above the diagonal, with its own dq/dkv backward, so the s x s
-score square never reaches HBM (``flash_ok`` says when).  Every other call
-takes plain jnp: ``_sdpa`` materializes the fp32 scores, and sequences longer
-than ``CHUNK_THRESHOLD`` go through ``_sdpa_chunked``, which maps over query
-chunks.  The in-repo forward-only kernel ``repro.kernels.flash_attention`` is
-on no step's path.
+score square never reaches HBM (``flash_ok`` says when).  Every other
+full-sequence call takes plain jnp: ``_sdpa`` materializes the fp32 scores,
+and sequences longer than ``CHUNK_THRESHOLD`` go through ``_sdpa_chunked``,
+which maps over query chunks.  Against a KV cache, ``gqa_decode`` states GQA
+attention once for C >= 1 new tokens: a generated token (C = 1) and a dense
+prefill chunk (C > 1) run the same jnp code; ``mla_decode`` is MLA's.
 """
 from __future__ import annotations
 
@@ -22,14 +23,11 @@ from repro.configs.base import ModelConfig
 from repro.distributed.sharding import current, hint
 from repro.models.common import rope, spec, softmax_fp32
 
-import os
-
 # seqs longer than this use the chunked (flash-style) path; below it the
 # plain einsum path avoids lax.map slicing a sharded seq dim (which forces
-# GSPMD into "involuntary full rematerialization" replication -- see
-# EXPERIMENTS.md §Perf iteration L1)
-CHUNK_THRESHOLD = int(os.environ.get("REPRO_ATTN_CHUNK_THRESHOLD", 8192))
-Q_CHUNK = int(os.environ.get("REPRO_ATTN_Q_CHUNK", 1024))
+# GSPMD into "involuntary full rematerialization" replication)
+CHUNK_THRESHOLD = 8192
+Q_CHUNK = 1024
 
 # The splash kernel's query and key blocks, forward and backward: the largest
 # of FLASH_BLOCK, FLASH_BLOCK/2, ... down to FLASH_MIN_BLOCK that divides the
@@ -72,12 +70,12 @@ def _sdpa(q, k, v, *, causal: bool, q_pos0: int = 0):
     """q (b,s,h,dk), k/v (b,t,m,dk|dv) -> (b,s,h,dv); GQA by head grouping.
 
     Wrapped in named_scope("flashrgn"), a marker for the dry-run analyzer
-    (``distributed/hlo_analysis.py``): it substitutes the Pallas flash
-    kernel's HBM I/O (kernels/flash_attention, validated vs this exact math)
-    for the score materialization of this jnp lowering.  This region runs as
-    plain XLA ops; on a TPU, causal GQA self-attention does not reach it but
-    runs as the splash kernel (``flash_sdpa``), so the analyzer's substitution
-    holds for CPU lowerings only.
+    (``distributed/hlo_analysis.py``): it substitutes a flash kernel's HBM
+    I/O for the score materialization of this jnp lowering.  This region runs
+    as plain XLA ops; on a TPU, causal GQA self-attention does not reach it
+    but runs as the splash kernel (``flash_sdpa``), so the analyzer's
+    substitution holds for CPU lowerings only.  ``_sdpa`` is also the
+    reference the kernel's tests compare against.
     """
     with jax.named_scope("flashrgn"):
         b, s, h, dk = q.shape
@@ -225,56 +223,25 @@ def gqa_attention(x, p, cfg: ModelConfig, *, causal: bool, positions,
                 "batch", "seq", "embed")
 
 
-def gqa_prefill_kv(x, p, cfg: ModelConfig, *, positions, use_rope: bool = True):
-    """K/V as stored in the decode cache."""
-    k = jnp.einsum("btd,dmk->btmk", x, p["wk"])
-    v = jnp.einsum("btd,dmk->btmk", x, p["wv"])
-    if use_rope:
-        k = rope(k, positions, cfg.rope_theta)
-    return k, v
+def gqa_decode(x, p, cfg: ModelConfig, cache_k, cache_v, start):
+    """GQA attention of C >= 1 new tokens against the KV cache.
 
-
-def gqa_decode(x1, p, cfg: ModelConfig, cache_k, cache_v, pos, *,
-               update_cache: bool = True, use_rope: bool = True):
-    """One-token decode. x1 (b,1,d); cache_k/v (b,S,m,dk). pos: scalar int."""
-    b, _, d = x1.shape
-    S, m = cache_k.shape[1], cache_k.shape[2]
-    q = jnp.einsum("bsd,dhk->bshk", x1, p["wq"])
-    if use_rope:
-        q = rope(q, jnp.full((1,), pos), cfg.rope_theta)
-    if update_cache:
-        k1 = jnp.einsum("bsd,dmk->bsmk", x1, p["wk"])
-        v1 = jnp.einsum("bsd,dmk->bsmk", x1, p["wv"])
-        if use_rope:
-            k1 = rope(k1, jnp.full((1,), pos), cfg.rope_theta)
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k1.astype(cache_k.dtype),
-                                               (0, pos, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v1.astype(cache_v.dtype),
-                                               (0, pos, 0, 0))
-    h, dk = q.shape[2], q.shape[3]
-    g = h // m
-    qg = q.reshape(b, m, g, dk)
-    cache_k = hint(cache_k, "batch", "kv_seq", "kv_heads", "head_dim")
-    cache_v = hint(cache_v, "batch", "kv_seq", "kv_heads", "head_dim")
-    scores = jnp.einsum("bmgk,btmk->bmgt", qg, cache_k) / (dk ** 0.5)
-    valid = jnp.arange(S)[None, None, None, :] <= pos
-    probs = softmax_fp32(scores, where=valid)
-    out = jnp.einsum("bmgt,btmv->bmgv", probs.astype(cache_v.dtype), cache_v)
-    out = out.reshape(b, 1, h, cache_v.shape[-1])
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache_k, cache_v
-
-
-def gqa_prefill_chunk(x, p, cfg: ModelConfig, cache_k, cache_v, start):
-    """``gqa_decode`` for C tokens at positions start .. start+C-1.
-
-    x (b,C,d); cache_k/v (b,S,m,dk); start: scalar int.  Writes the chunk's
-    K/V at ``start``, then scores each query against the whole cache, masked
-    to the positions at or before its own."""
+    x (b,C,d) at positions start .. start+C-1; cache_k/v (b,S,m,dk); start:
+    scalar int.  RoPE at those positions on q and on the new K/V, which are
+    written into the cache at ``start``; then each query scores against the
+    whole cache, masked to the positions at or before its own.  A generated
+    token is the C = 1 case, a prefill chunk C > 1.  Each KV head scores its
+    g query heads times C tokens as one (g, C) block of rows, (b,m,g,C,S);
+    the einsums leave XLA to fold the query and output transposes into the
+    dots, which an explicit (b,m,g*C,dk) transpose of q would not.
+    -> (out (b,C,d), cache_k, cache_v)."""
     b, c, _ = x.shape
     S, m = cache_k.shape[1], cache_k.shape[2]
     positions = start + jnp.arange(c)
     q = rope(jnp.einsum("bsd,dhk->bshk", x, p["wq"]), positions, cfg.rope_theta)
-    k, v = gqa_prefill_kv(x, p, cfg, positions=positions)
+    k = jnp.einsum("bsd,dmk->bsmk", x, p["wk"])
+    v = jnp.einsum("bsd,dmk->bsmk", x, p["wv"])
+    k = rope(k, positions, cfg.rope_theta)
     cache_k = jax.lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype),
                                            (0, start, 0, 0))
     cache_v = jax.lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype),

@@ -424,13 +424,33 @@ def scan_decode(body, x0, xs, cache):
     return x, jax.tree.unflatten(tdef, leaves)
 
 
-def _attn_block_decode(x1, p, cfg, ck, cv, pos, attend=attn.gqa_decode):
-    h = rms_norm(x1, p["ln1"], cfg.norm_eps)
+def _attn_block_decode(x, p, cfg, ck, cv, start):
+    h = rms_norm(x, p["ln1"], cfg.norm_eps)
     with jax.named_scope("attn"):
-        a, ck, cv = attend(h, p["attn"], cfg, ck, cv, pos)
-    x1 = x1 + a
-    h = rms_norm(x1, p["ln2"], cfg.norm_eps)
-    return x1 + mlp_apply(h, p["mlp"], cfg), ck, cv
+        a, ck, cv = attn.gqa_decode(h, p["attn"], cfg, ck, cv, start)
+    x = x + a
+    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    return x + mlp_apply(h, p["mlp"], cfg), ck, cv
+
+
+def _dense_cached(params, cache, x, start, cfg):
+    """The dense layers over x (b,C,d) at positions start .. start+C-1
+    against the {"k","v"} cache: a generated token (C = 1) or a prefill
+    chunk.  -> (x, new cache)."""
+    def body(carry, bp, sl):
+        y, ck, cv = _attn_block_decode(carry, bp, cfg, sl["k"], sl["v"], start)
+        return y, {"k": ck, "v": cv}
+    return scan_decode(body, x, params["blocks"],
+                       {"k": cache["k"], "v": cache["v"]})
+
+
+def _head(params, x, cfg):
+    """Final norm and fp32 unembedding of x (b,1,d) -> logits (b,v)."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
+                            preferred_element_type=jnp.float32)
+    return logits[:, 0, :]
 
 
 def _ssm_block_decode(x1, p, cfg, cache):
@@ -446,12 +466,7 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig):
     x = jnp.take(params["embed"], token[:, None], axis=0)  # (b,1,d)
 
     if fam == "dense":
-        def body(carry, bp, sl):
-            y, ck, cv = _attn_block_decode(carry, bp, cfg, sl["k"], sl["v"],
-                                           pos)
-            return y, {"k": ck, "v": cv}
-        x, cache = scan_decode(body, x, params["blocks"],
-                               {"k": cache["k"], "v": cache["v"]})
+        x, cache = _dense_cached(params, cache, x, pos, cfg)
 
     elif fam == "moe":
         groups = 1
@@ -554,12 +569,7 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig):
                                {k: cache[k] for k in cache})
     else:
         raise ValueError(f"{fam} does not support decode")
-
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
-                            preferred_element_type=jnp.float32)
-    return logits[:, 0, :], cache
+    return _head(params, x, cfg), cache
 
 
 def chunked_prefill(cfg: ModelConfig) -> bool:
@@ -578,16 +588,6 @@ def prefill_chunk(params, cache, tokens, start, last, cfg: ModelConfig):
     if not chunked_prefill(cfg):
         raise NotImplementedError(f"chunked prefill for family {cfg.family!r}")
     x = jnp.take(params["embed"], tokens, axis=0)  # (b,C,d)
-
-    def body(carry, bp, sl):
-        y, ck, cv = _attn_block_decode(carry, bp, cfg, sl["k"], sl["v"], start,
-                                       attend=attn.gqa_prefill_chunk)
-        return y, {"k": ck, "v": cv}
-    x, cache = scan_decode(body, x, params["blocks"],
-                           {"k": cache["k"], "v": cache["v"]})
-    x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
-                            preferred_element_type=jnp.float32)
-    return logits[:, 0, :], cache
+    x, cache = _dense_cached(params, cache, x, start, cfg)
+    return _head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
+                 cfg), cache

@@ -3,13 +3,15 @@
 ``Generator`` serves a batch of prompts: a prefill that writes the prompt
 into the cache, then greedy or temperature sampling through ``decode_step``.
 Dense models prefill through ``prefill_chunk``, ``PREFILL_CHUNK`` prompt
-positions a call; every other family feeds the prompt through
-``decode_step`` one position at a time.  The same ``serve_step`` is what the
-decode_32k / long_500k dry-run shapes lower, so everything here runs
-identically under `jit` on the production mesh.  Host spans in the
-profiler's trace name each phase: ``prefill`` around the prompt, then per
-generated token ``sample``, ``token_fetch`` (the host waits for the token)
-and ``decode``.
+positions a call, which runs the same layer loop and cached attention
+(``models.attention.gqa_decode``) as a decode step over a chunk of tokens
+instead of one; every other family feeds the prompt through
+``decode_step`` one position at a time.  The dry-run's decode shapes
+(decode_32k, long_500k) lower the same ``Model.decode_step`` on the
+production mesh through ``distributed.step.build_serve_step``; ``Generator``
+jits it on one device.  Host spans in the profiler's trace name each
+phase: ``prefill`` around the prompt, then per generated token ``sample``,
+``token_fetch`` (the host waits for the token) and ``decode``.
 
 Traffic-scale serving lives next door (DESIGN.md §14): open-loop arrival
 processes in :mod:`repro.serving.arrivals`, the analytic per-step

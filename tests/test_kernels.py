@@ -6,8 +6,6 @@ import pytest
 
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.quant8.ops import dequantize8, int8_roundtrip, quantize8
 from repro.kernels.quant8.ref import quantize8_ref
 from repro.kernels.topk_ef.ops import topk_ef
@@ -21,41 +19,6 @@ RNG = np.random.default_rng(7)
 
 def _arr(shape, dtype=jnp.float32, scale=1.0):
     return jnp.asarray(RNG.standard_normal(shape) * scale, dtype)
-
-
-# ------------------------------------------------------------ flash ----------
-
-@pytest.mark.parametrize("b,sq,sk,h,m,d,causal,dtype", [
-    (2, 256, 256, 4, 2, 64, True, jnp.float32),
-    (1, 512, 512, 2, 2, 128, False, jnp.float32),
-    (2, 128, 128, 3, 1, 32, True, jnp.float32),
-    (1, 256, 256, 8, 4, 64, True, jnp.bfloat16),
-    (1, 384, 384, 2, 1, 128, True, jnp.float32),
-])
-def test_flash_attention(b, sq, sk, h, m, d, causal, dtype):
-    q, k, v = (_arr((b, sq, h, d), dtype), _arr((b, sk, m, d), dtype),
-               _arr((b, sk, m, d), dtype))
-    o = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128,
-                        interpret=True)
-    g = h // m
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = jnp.repeat(k.transpose(0, 2, 1, 3), g, 1).reshape(b * h, sk, d)
-    vf = jnp.repeat(v.transpose(0, 2, 1, 3), g, 1).reshape(b * h, sk, d)
-    ref = attention_ref(qf, kf, vf, causal=causal, sm_scale=d ** -0.5)
-    ref = ref.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(ref, np.float32), atol=tol, rtol=tol)
-
-
-def test_flash_attention_blocks_irrelevant():
-    """Block-shape sweep: numerics must not depend on tiling."""
-    q, k, v = _arr((1, 512, 2, 64)), _arr((1, 512, 2, 64)), _arr((1, 512, 2, 64))
-    outs = [flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
-                            interpret=True)
-            for bq, bk in [(64, 64), (128, 256), (512, 128)]]
-    for o in outs[1:]:
-        np.testing.assert_allclose(outs[0], o, atol=1e-5, rtol=1e-5)
 
 
 # ------------------------------------------------------------ decode ---------

@@ -1,6 +1,8 @@
 """Model-zoo correctness: decode-vs-forward consistency (the strongest cache
-test), SSD chunked-vs-recurrence, MLA absorbed decode, conv cache, and the
-splash kernel path of causal self-attention against the jnp path."""
+test), cached GQA attention of several tokens against one at a time and
+against ``_sdpa``, SSD chunked-vs-recurrence, MLA absorbed decode, conv
+cache, and the splash kernel path of causal self-attention against the jnp
+path."""
 import functools
 
 import jax
@@ -13,7 +15,7 @@ from repro.configs import ARCH_IDS, get_reduced
 from repro.distributed.sharding import ShardingCtx, use_sharding
 from repro.launch.specs import make_batch
 from repro.models import attention, build_model
-from repro.models.common import init_params
+from repro.models.common import init_params, rope
 from repro.models.ssm import _conv1d_causal, ssd_scan
 
 DECODE_ARCHS = [n for n in ARCH_IDS if n != "hubert-xlarge"]
@@ -174,22 +176,24 @@ def _rel(a, b):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("h,m,s,block", [(15, 5, 256, None), (6, 2, 256, None),
-                                         (15, 5, 512, 128)],
-                         ids=["15-5", "6-2", "15-5-s512-block128"])
-def test_flash_sdpa_matches_sdpa(monkeypatch, h, m, s, block, dtype):
+@pytest.mark.parametrize("h,m,s,block,d,b", [
+    (15, 5, 256, None, 64, 1), (6, 2, 256, None, 64, 1),
+    (15, 5, 512, 128, 64, 1), (2, 1, 384, 128, 128, 1),
+    (4, 2, 256, None, 64, 2)],
+    ids=["15-5", "6-2", "15-5-s512-block128", "2-1-s384-block128-d128",
+         "4-2-b2"])
+def test_flash_sdpa_matches_sdpa(monkeypatch, h, m, s, block, d, b, dtype):
     """The splash kernel (interpret mode) against the jnp ``_sdpa`` at a GQA
-    shape, head_dim 64, causal: the output and the gradients of q, k and v.
-    At s = 256 the kernel runs one 256 block; with 128 blocks at s = 512 it
-    runs 4 x 4, so the online softmax across key blocks, the skipping of the
-    blocks above the diagonal and the dq/dkv backward over several blocks
-    are checked too.  In bf16 both are held to a float32 reference, and the
-    kernel (fp32 scores) is at least as close to it as ``_sdpa`` (bf16
-    scores)."""
+    shape, causal: the output and the gradients of q, k and v.  At s = 256
+    the kernel runs one 256 block; with 128 blocks at s = 512 it runs 4 x 4,
+    and at s = 384 3 x 3 with head_dim 128, so the online softmax across key
+    blocks, the skipping of the blocks above the diagonal and the dq/dkv
+    backward over several blocks are checked too; b = 2 maps the kernel over
+    a batch.  In bf16 both are held to a float32 reference, and the kernel
+    (fp32 scores) is at least as close to it as ``_sdpa`` (bf16 scores)."""
     if block:
         monkeypatch.setattr(attention, "FLASH_BLOCK", block)
     assert attention._flash_block(s) == (block or s)
-    b, d = 1, 64
     ks = jax.random.split(jax.random.key(0), 4)
     q = jax.random.normal(ks[0], (b, s, h, d), jnp.float32)
     k = jax.random.normal(ks[1], (b, s, m, d), jnp.float32)
@@ -220,6 +224,41 @@ def test_flash_sdpa_matches_sdpa(monkeypatch, h, m, s, block, dtype):
     for a, r, f32 in zip(got, base, ref):
         assert _rel(a, r) < 1e-2
         assert _rel(a, f32) <= 1.05 * _rel(r, f32)
+
+
+@pytest.mark.parametrize("start", [0, 5])
+@pytest.mark.parametrize("c", [1, 3, 8])
+def test_gqa_decode_chunk_matches_tokens_and_sdpa(c, start):
+    """``gqa_decode`` of C tokens at positions start .. start+C-1 in one call
+    gives the output and cache of C one-token calls, and the output of
+    causal ``_sdpa`` over the whole prefix (fp32)."""
+    cfg = get_reduced("smollm-360m").model.replace(
+        dtype="float32", d_model=64, num_heads=6, num_kv_heads=2, head_dim=16)
+    b, S, m, dk = 2, 16, cfg.kv_heads, cfg.hdim
+    p = init_params(jax.random.key(4), attention.gqa_spec(cfg), "float32")
+    x = jax.random.normal(jax.random.key(5), (b, start + c, cfg.d_model))
+    cache = (jnp.zeros((b, S, m, dk)),) * 2
+    if start:
+        _, *cache = attention.gqa_decode(x[:, :start], p, cfg, *cache, 0)
+    out, ck, cv = attention.gqa_decode(x[:, start:], p, cfg, *cache, start)
+    assert out.shape == (b, c, cfg.d_model)
+
+    outs, tk, tv = [], *cache
+    for t in range(start, start + c):
+        o, tk, tv = attention.gqa_decode(x[:, t:t + 1], p, cfg, tk, tv, t)
+        outs.append(o)
+    np.testing.assert_allclose(out, jnp.concatenate(outs, 1), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(ck, tk, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(cv, tv, rtol=1e-6, atol=1e-6)
+    assert not np.any(np.asarray(ck[:, start + c:]))
+
+    pos = jnp.arange(start + c)
+    q = rope(jnp.einsum("bsd,dhk->bshk", x, p["wq"]), pos, cfg.rope_theta)
+    k = rope(jnp.einsum("bsd,dmk->bsmk", x, p["wk"]), pos, cfg.rope_theta)
+    v = jnp.einsum("bsd,dmk->bsmk", x, p["wv"])
+    ref = attention._sdpa(q, k, v, causal=True)[:, start:]
+    ref = jnp.einsum("bshk,hkd->bsd", ref, p["wo"])
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
 
 GQA_CASES = ["causal_self", "bidirectional", "cross", "ragged", "model_axis",
